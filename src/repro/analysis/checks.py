@@ -19,7 +19,6 @@ committed baseline.
 """
 from __future__ import annotations
 
-import contextlib
 import re
 from typing import Any, Iterator
 
@@ -29,10 +28,7 @@ import numpy as np
 from repro.analysis.registry import ProgramSpec
 from repro.utils.hlo import collective_stats, input_output_aliases
 
-try:  # the supported extension point for jaxpr types
-    from jax.extend import core as _jcore
-except ImportError:  # pragma: no cover - very old jax
-    from jax import core as _jcore  # type: ignore[no-redef]
+from jax.extend import core as _jcore
 
 COLLECTIVE_OPS = (
     "all-reduce",
@@ -69,24 +65,6 @@ _F64_ANY_RE = re.compile(r"[<x](?:f64|complex<f64>)")
 _F64_RANKED_RE = re.compile(r"tensor<(?:\?|\d)[x0-9?]*x(?:f64|complex<f64>)>")
 
 
-def _x64_ctx(enable: bool):
-    try:
-        from jax.experimental import disable_x64, enable_x64
-
-        return enable_x64() if enable else disable_x64()
-    except ImportError:  # pragma: no cover - future jax without the ctx
-        @contextlib.contextmanager
-        def _ctx():
-            prev = jax.config.jax_enable_x64
-            jax.config.update("jax_enable_x64", enable)
-            try:
-                yield
-            finally:
-                jax.config.update("jax_enable_x64", prev)
-
-        return _ctx()
-
-
 def _as_jitted(fn: Any):
     return fn if hasattr(fn, "lower") else jax.jit(fn)
 
@@ -110,7 +88,7 @@ class ProgramArtifacts:
 
     def _built(self):
         if self._fn is None:
-            with _x64_ctx(False):
+            with jax.enable_x64(False):
                 self._fn, self._args = self.spec.build()
             self._fn = _as_jitted(self._fn)
         return self._fn, self._args
@@ -119,14 +97,14 @@ class ProgramArtifacts:
     def jaxpr(self):
         if self._jaxpr is None:
             fn, args = self._built()
-            with _x64_ctx(False):
+            with jax.enable_x64(False):
                 self._jaxpr = fn.trace(*args).jaxpr
         return self._jaxpr
 
     def stablehlo(self, x64: bool) -> str:
         if x64 not in self._stablehlo:
             fn, args = self._built()
-            with _x64_ctx(x64):
+            with jax.enable_x64(x64):
                 self._stablehlo[x64] = fn.lower(*args).as_text()
         return self._stablehlo[x64]
 
@@ -134,7 +112,7 @@ class ProgramArtifacts:
     def compiled_text(self) -> str:
         if self._compiled_text is None:
             fn, args = self._built()
-            with _x64_ctx(False):
+            with jax.enable_x64(False):
                 self._compiled_text = fn.lower(*args).compile().as_text()
         return self._compiled_text
 

@@ -22,10 +22,9 @@ _N, _J, _D = 1024, 2, 8  # rows, dims, basis width for the toy programs
 def _build_extra_psum():
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from repro.utils.compat import make_mesh
+    from repro.utils.compat import make_mesh, shard_map
 
     mesh = make_mesh((jax.device_count(),), ("data",))
 

@@ -96,11 +96,14 @@ def transform_parts(
     cfg: MCTMConfig, params: MCTMParams, A: jax.Array, Ap: jax.Array
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Return (z, h̃, h̃′): copula inputs and marginal transform/derivative."""
+    # full f32 products: a TPU's default single bf16 pass moves per-point
+    # log-densities by ~1e-3 relative
+    hi = jax.lax.Precision.HIGHEST
     theta = monotone_theta(params.theta_raw, cfg.min_slope)  # (J, d)
-    htilde = jnp.einsum("njd,jd->nj", A, theta)
-    hprime = jnp.einsum("njd,jd->nj", Ap, theta)
+    htilde = jnp.einsum("njd,jd->nj", A, theta, precision=hi)
+    hprime = jnp.einsum("njd,jd->nj", Ap, theta, precision=hi)
     Lam = lambda_matrix(cfg, params.lam)
-    z = htilde @ Lam.T  # z_ij = Σ_{k≤j} λ_{jk} h̃_k(y_ik)
+    z = jnp.dot(htilde, Lam.T, precision=hi)  # z_ij = Σ_{k≤j} λ_{jk} h̃_k(y_ik)
     return z, htilde, hprime
 
 

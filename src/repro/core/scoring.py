@@ -132,6 +132,11 @@ __all__ = [
 
 DEFAULT_CHUNK = 65_536
 
+# f32 matmuls on a TPU default to one bf16 pass. Leverage reads small-
+# eigenvalue modes of an ill-conditioned Gram and the hull net is built from
+# the P moments, so those products ask for full f32.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 SCORE_METHODS = ("l2-only", "l2-hull", "ridge-lss", "root-l2")
 GRAM_DTYPES = ("float32", "float64")
 
@@ -248,14 +253,14 @@ def pass1_update(G, s1, s2, X, P, sw, gram_dtype: str | None = None):
         G = G + gram_matrix(Xw)
     if P is not None:
         s1 = s1 + jnp.sum(P, axis=0)
-        s2 = s2 + P.T @ P
+        s2 = s2 + jnp.dot(P.T, P, precision=HIGHEST)
     return G, s1, s2
 
 
 def leverage_chunk(X, sw, V, inv):
     """u_i = Σ_m ((√w·X)_i V)²_m · inv_m for one chunk of rows. Pure."""
     Xw = X * sw[:, None]
-    return jnp.sum(jnp.square(Xw @ V) * inv, axis=1)
+    return jnp.sum(jnp.square(jnp.dot(Xw, V, precision=HIGHEST)) * inv, axis=1)
 
 
 def hull_chunk_extremes(P, dirs, mask=None):
@@ -274,7 +279,7 @@ def hull_chunk_extremes(P, dirs, mask=None):
 def _moments_update(s1, s2, P):
     """Hull-moment half of ``pass1_update`` (the f64-Gram host path still
     accumulates moments on device in f32). Pure."""
-    return s1 + jnp.sum(P, axis=0), s2 + P.T @ P
+    return s1 + jnp.sum(P, axis=0), s2 + jnp.dot(P.T, P, precision=HIGHEST)
 
 
 def _sketch_update(SX, s1, s2, X, P, sw, rows, signs):
@@ -295,7 +300,7 @@ def _weighted_project(X, sw, omega):
 
 def _z_leverage(z, V, inv):
     """Leverage read-off from stored (already √w-scaled) row blocks. Pure."""
-    return jnp.sum(jnp.square(z @ V) * inv, axis=1)
+    return jnp.sum(jnp.square(jnp.dot(z, V, precision=HIGHEST)) * inv, axis=1)
 
 
 _acc_stats = jax.jit(pass1_update, static_argnames=("gram_dtype",))

@@ -6,11 +6,13 @@ it to completion. Contract:
 
   * **What is retried.** Any ``RuntimeError`` raised by the attempt — that
     family covers ``InjectedFailure``, ``NonFiniteError`` and jax's
-    ``XlaRuntimeError`` (dead peer / barrier timeout / device loss).
+    ``JaxRuntimeError`` (dead peer / barrier timeout / device loss).
     ``ValueError``/``TypeError``/``KeyboardInterrupt`` and friends are
     programming or user errors and propagate immediately, as do
     ``NotImplementedError``/``RecursionError`` (RuntimeError subclasses that
-    are never transient).
+    are never transient) and the ``JaxRuntimeError``s that would fail the
+    same way on every attempt: a program the XLA or Mosaic compiler refuses,
+    and one that does not fit in device memory (``RESOURCE_EXHAUSTED``).
   * **What triggers re-planning.** When a planner is attached, every retry
     consults ``ElasticPlanner.plan(n_alive)`` with the currently visible
     device count and rebuilds the mesh (``mesh_from_plan``, or a caller
@@ -34,6 +36,7 @@ import time
 from typing import Callable, Optional
 
 import numpy as np
+from jax.errors import JaxRuntimeError
 
 from repro.ft.config import FTConfig, get_ft_config
 from repro.ft.failure import ElasticPlanner, MeshPlan, NonFiniteError
@@ -42,6 +45,25 @@ __all__ = ["RunContext", "RunSupervisor", "mesh_from_plan"]
 
 # RuntimeError subclasses that are never transient infrastructure faults
 _NON_RETRYABLE = (NotImplementedError, RecursionError)
+
+# XLA status codes that repeat identically on every attempt: out of device
+# memory, and programs the compiler rejects. Device loss and collective
+# timeouts arrive under other codes (UNAVAILABLE, DEADLINE_EXCEEDED, ...).
+_PERMANENT_XLA_CODES = (
+    "RESOURCE_EXHAUSTED",
+    "INVALID_ARGUMENT",
+    "UNIMPLEMENTED",
+    "FAILED_PRECONDITION",
+)
+
+
+def _is_permanent_xla_error(exc: BaseException) -> bool:
+    """A compile refusal (XLA or Mosaic, whatever its status code) or an
+    out-of-memory error: retrying would only repeat it after the backoff."""
+    if not isinstance(exc, JaxRuntimeError):
+        return False
+    msg = str(exc)
+    return msg.startswith(_PERMANENT_XLA_CODES) or "compile" in msg.lower()
 
 
 def mesh_from_plan(plan: MeshPlan, devices=None):
@@ -96,7 +118,11 @@ class RunSupervisor:
 
     @staticmethod
     def _retryable(exc: BaseException) -> bool:
-        return isinstance(exc, RuntimeError) and not isinstance(exc, _NON_RETRYABLE)
+        return (
+            isinstance(exc, RuntimeError)
+            and not isinstance(exc, _NON_RETRYABLE)
+            and not _is_permanent_xla_error(exc)
+        )
 
     def _n_alive(self) -> int:
         if self.devices_fn is not None:

@@ -22,6 +22,9 @@ from jax.experimental import pallas as pl
 
 DEFAULT_BLOCK_ROWS = 512
 LANE = 128
+# Mosaic's default for an f32 dot is a single bf16 pass (inputs rounded to an
+# 8-bit mantissa); the scoring statistics need f32, so every dot asks for it
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _kernel(p_ref, d_ref, nv_ref, vmax_ref, imax_ref, vmin_ref, imin_ref,
@@ -39,7 +42,7 @@ def _kernel(p_ref, d_ref, nv_ref, vmax_ref, imax_ref, vmin_ref, imin_ref,
     # dim; zero-padded lanes contribute nothing
     S = jax.lax.dot_general(
         d_ref[...], p_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        precision=HIGHEST, preferred_element_type=jnp.float32,
     )
     base = i * block_rows
     ridx = base + jax.lax.broadcasted_iota(jnp.int32, S.shape, 1)

@@ -14,6 +14,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 DEFAULT_BLOCK_ROWS = 256
+# Mosaic's default for an f32 dot is a single bf16 pass (inputs rounded to an
+# 8-bit mantissa); the scoring statistics need f32, so every dot asks for it
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _kernel(x_ref, g_ref):
@@ -23,7 +26,8 @@ def _kernel(x_ref, g_ref):
 
     x = x_ref[...].astype(jnp.float32)
     g_ref[...] += jax.lax.dot_general(
-        x, x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        x, x, (((0,), (0,)), ((), ())),
+        precision=HIGHEST, preferred_element_type=jnp.float32,
     )
 
 

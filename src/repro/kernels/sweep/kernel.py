@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.extremes.kernel import DEFAULT_BLOCK_ROWS, LANE  # noqa: F401
+from repro.kernels.extremes.kernel import DEFAULT_BLOCK_ROWS, HIGHEST, LANE  # noqa: F401
 
 
 def _kernel(*refs, block_rows: int, r: int, has_p: bool, hull: bool,
@@ -85,14 +85,15 @@ def _kernel(*refs, block_rows: int, r: int, has_p: bool, hull: bool,
         0.0,
     )
     dsx_ref[...] += jax.lax.dot_general(
-        E, Xw, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        E, Xw, (((1,), (0,)), ((), ())),
+        precision=HIGHEST, preferred_element_type=jnp.float32,
     )
 
     if want_z:
         z_ref[...] = (
             jax.lax.dot_general(
                 Xw, omega_ref[...], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
+                precision=HIGHEST, preferred_element_type=jnp.float32,
             )
             if has_omega
             else Xw
@@ -104,7 +105,7 @@ def _kernel(*refs, block_rows: int, r: int, has_p: bool, hull: bool,
         s1_ref[...] += jnp.sum(Pb, axis=0)[None, :]
         s2_ref[...] += jax.lax.dot_general(
             Pb, Pb, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            precision=HIGHEST, preferred_element_type=jnp.float32,
         )
 
     if hull:
@@ -112,7 +113,7 @@ def _kernel(*refs, block_rows: int, r: int, has_p: bool, hull: bool,
         # with the validity count in points scaled to P rows
         S = jax.lax.dot_general(
             dirs_ref[...], p_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            precision=HIGHEST, preferred_element_type=jnp.float32,
         )
         base = i * block_rows * r
         ridx = base + jax.lax.broadcasted_iota(jnp.int32, S.shape, 1)

@@ -182,7 +182,10 @@ def run(args) -> dict:
 
 
 def main(argv=None):
+    from repro.utils.compile_cache import enable_compile_cache
+
     args = parse_args(argv)
+    enable_compile_cache()
     rec = run(args)
     ok = (
         rec["dropped"] == 0

@@ -105,7 +105,9 @@ def parse_args(argv=None):
     return args
 
 
-def run(args) -> dict:
+def run(args, mesh=None) -> dict:
+    """The experiment (module doc) on ``mesh`` (default: a data mesh over
+    every device). Returns the record it writes."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -125,8 +127,10 @@ def run(args) -> dict:
     from repro.ft.config import get_ft_config
     from repro.launch.stages import data_mesh
 
-    mesh = data_mesh()
+    if mesh is None:
+        mesh = data_mesh()
     devices = int(np.prod(list(mesh.shape.values())))
+    dev0 = mesh.devices.flat[0]
 
     ft_cfg = get_ft_config()
     sim = None
@@ -169,7 +173,8 @@ def run(args) -> dict:
     if args.strategy == "one-pass" and sketch == 0:
         sketch = 4 * D * D
 
-    print(f"[train_mctm] dgp={args.dgp} n={args.n} devices={devices} "
+    print(f"[train_mctm] platform={dev0.platform} device_kind={dev0.device_kind} "
+          f"dgp={args.dgp} n={args.n} devices={devices} "
           f"strategy={args.strategy} sketch={sketch} steps={args.steps} "
           f"fit={args.fit_method} ref={args.ref_method}",
           flush=True)
@@ -320,7 +325,10 @@ def run(args) -> dict:
 
 
 def main(argv=None):
+    from repro.utils.compile_cache import enable_compile_cache
+
     args = parse_args(argv)
+    enable_compile_cache()
     # force a multi-device CPU mesh BEFORE the first jax device query — the
     # sharded stages then genuinely shard on the container (same mechanism as
     # launch.dryrun); skipped when real accelerators are present

@@ -1,57 +1,26 @@
-"""Version compatibility shims for the jax API surface this repo targets.
-
-The code is written against current jax (`jax.shard_map`, `jax.make_mesh`
-with ``axis_types``, ``check_vma``); CI images may carry an older 0.4.x where
-those names live elsewhere or don't exist. Import the symbols from here so
-every module (and the subprocess-isolated distributed tests) resolves them
-uniformly.
+"""The two mesh entry points every module imports: ``shard_map`` and
+``make_mesh``, thin calls of ``jax.shard_map`` / ``jax.make_mesh`` that fix
+the conventions this repo uses (Auto axis types unless ``explicit``; the
+replication check passed only when a caller sets it).
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
 
-__all__ = ["shard_map", "make_mesh", "MIN_JAX_VERSION"]
-
-# The oldest jax this repo supports — the version every shim below exists
-# for. CI's version matrix pins its minimum leg to exactly this (the
-# workflow asserts the installed jax matches, so the pin cannot silently
-# drift from the shims).
-MIN_JAX_VERSION = "0.4.37"
-
-try:  # jax >= 0.5
-    from jax import shard_map as _shard_map
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-check kwarg was renamed check_rep → check_vma independently
-# of where shard_map lives, so probe the signature rather than the import path
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
+__all__ = ["shard_map", "make_mesh"]
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """`jax.shard_map` with the replication-check kwarg renamed per version."""
-    kw = {}
-    if check_vma is not None:
-        kw[_CHECK_KW] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    """`jax.shard_map`; ``check_vma=None`` keeps jax's default."""
+    kw = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
 
 
-def make_mesh(axis_shapes, axis_names, *, explicit: bool = False):
-    """`jax.make_mesh` requesting Auto axis types where supported.
-
-    Older jax has no ``axis_types`` kwarg (Auto is the only behavior); newer
-    jax defaults to Auto unless ``explicit``.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
-    kind = axis_type.Explicit if explicit else axis_type.Auto
+def make_mesh(axis_shapes, axis_names, *, explicit: bool = False, devices=None):
+    """`jax.make_mesh` with every axis Auto (or Explicit when ``explicit``),
+    over ``devices`` when given (default: all of ``jax.devices()``)."""
+    kind = jax.sharding.AxisType.Explicit if explicit else jax.sharding.AxisType.Auto
     return jax.make_mesh(
-        tuple(axis_shapes), tuple(axis_names), axis_types=(kind,) * len(axis_names)
+        tuple(axis_shapes), tuple(axis_names),
+        axis_types=(kind,) * len(axis_names), devices=devices,
     )

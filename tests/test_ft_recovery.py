@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.errors import JaxRuntimeError
 
 from repro.checkpoint import CheckpointManager
 from repro.core import mctm as M
@@ -101,8 +102,13 @@ def test_supervisor_budget_exhausted_diagnostic_includes_injection_log():
     assert isinstance(ei.value.__cause__, InjectedFailure)
 
 
-@pytest.mark.parametrize("exc", [ValueError("bad"), TypeError("bad"),
-                                 NotImplementedError("bad")])
+@pytest.mark.parametrize("exc", [
+    ValueError("bad"), TypeError("bad"), NotImplementedError("bad"),
+    # compile refusals and out-of-memory repeat identically on every attempt
+    JaxRuntimeError("RESOURCE_EXHAUSTED: Ran out of memory in memory space hbm"),
+    JaxRuntimeError("INTERNAL: Mosaic failed to compile TPU kernel: bad tiling"),
+    JaxRuntimeError("INVALID_ARGUMENT: unsupported operand layout"),
+])
 def test_supervisor_non_retryable_propagates_immediately(exc):
     sup = RunSupervisor()
     calls = []
@@ -114,6 +120,25 @@ def test_supervisor_non_retryable_propagates_immediately(exc):
     with pytest.raises(type(exc)):
         sup.run(attempt)
     assert calls == [0]  # no retry burned on a programming error
+
+
+def test_supervisor_retries_transient_xla_errors():
+    """Device loss / collective timeouts stay retryable: only compile
+    refusals and out-of-memory are permanent."""
+    sup = RunSupervisor(sleep=lambda s: None)
+    calls = []
+
+    def attempt(ctx):
+        calls.append(ctx.attempt)
+        if ctx.attempt == 0:
+            raise JaxRuntimeError("UNAVAILABLE: peer task is gone")
+        if ctx.attempt == 1:
+            raise JaxRuntimeError("DEADLINE_EXCEEDED: barrier timed out")
+        return "ok"
+
+    with ft_overrides(max_retries=3, backoff_base_s=0.0):
+        assert sup.run(attempt) == "ok"
+    assert calls == [0, 1, 2]
 
 
 def test_supervisor_nonfinite_backs_off_lr_without_replanning():

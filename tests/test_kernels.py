@@ -22,10 +22,22 @@ from repro.kernels.ssd.ref import ssd_ref
 def test_bernstein_kernel_sweep(n, degree):
     rng = np.random.default_rng(n * 10 + degree)
     t = jnp.asarray(rng.random(n), jnp.float32)
-    basis, deriv = bernstein_basis_deriv(t, degree)
+    # interpret=True: the Pallas kernel itself (off-TPU the default backend is
+    # the jnp oracle, which would compare ref to ref)
+    basis, deriv = bernstein_basis_deriv(t, degree, interpret=True)
     bref, dref = bernstein_basis_deriv_ref(t, degree)
     np.testing.assert_allclose(np.asarray(basis), np.asarray(bref), atol=1e-5)
     np.testing.assert_allclose(np.asarray(deriv), np.asarray(dref), atol=1e-4)
+
+
+def test_bernstein_default_backend_is_oracle_off_tpu():
+    """No silent interpret mode: off-TPU the default is the jnp oracle, and
+    an unknown backend is refused."""
+    t = jnp.linspace(0.0, 1.0, 37, dtype=jnp.float32)
+    for got, ref in zip(bernstein_basis_deriv(t, 5), bernstein_basis_deriv_ref(t, 5)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    with pytest.raises(ValueError, match="unknown bernstein backend"):
+        bernstein_basis_deriv(t, 5, backend="cuda")
 
 
 # --------------------------------------------------------------------- gram
