@@ -1,0 +1,106 @@
+"""Discovery by name, the refusals, and BENCHMARK.json's own consistency.
+
+Runs on the CPU and loads no TPU library: the chip checks are driven with
+JAX's CPU backend or with a stand-in device."""
+from __future__ import annotations
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import chipbench_testlib as lib  # noqa: E402
+
+from chipbench import run  # noqa: E402
+
+
+def _bench():
+    with open(os.path.join(lib.ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def test_every_cell_resolves_to_its_files():
+    bench = _bench()
+    for wl in bench["workloads"]:
+        cell = run.find_cell(wl["name"])
+        assert cell.config["name"] == wl["config"]
+        assert callable(cell.entry.call) and callable(cell.entry.check)
+        assert cell.limits, f"{wl['name']} has no limits file"
+        names = {m["name"] for m in cell.end_to_end}
+        assert "setup_s" in names and len(names) >= 2
+        assert cell.per_layer
+        for m in cell.end_to_end + cell.per_layer:
+            if m["name"] != "setup_s":
+                assert callable(run.metric_reader(cell, m["name"]).read)
+
+
+def test_per_layer_metrics_move_a_metric_their_cells_report():
+    bench = _bench()
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    for m in bench["per_layer"]:
+        moved = e2e[m["moves"]]
+        assert set(m["workloads"]) <= set(moved.get("workloads", m["workloads"]))
+
+
+def test_throwaway_config_and_mix_are_found_from_new_files(tmp_path):
+    root = lib.tiny_bench(tmp_path)
+    cell = run.find_cell("tiny.build.two_pass", root=root)
+    assert cell.config["name"] == "tiny_j2" and cell.config["n"] == 20001
+    assert cell.traffic["entry"] == "build" and cell.traffic["check_calls"] == 1
+    assert cell.limits["lev_tv"] == 4e-4
+    assert [m["name"] for m in cell.end_to_end] == ["build_rows_per_s", "setup_s"]
+    assert "idle_share.build" in [m["name"] for m in cell.per_layer]
+    # the copy's files are the ones loaded, and nothing in the repo changed
+    assert cell.bench_dir == os.path.join(root, "chipbench")
+    assert not os.path.exists(os.path.join(lib.BENCH, "configs", "tiny_j2.json"))
+    assert not os.path.exists(os.path.join(lib.BENCH, "traffic", "tiny_two_pass.json"))
+
+
+def test_run_refuses_a_cpu_backend():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(lib.BENCH, "run.py"), "--workload",
+         "build.j2_mixture.two_pass", "--seed", "1", "--seconds", "1", "--trace", "0"],
+        capture_output=True, text=True, env=env, timeout=120, cwd=lib.ROOT)
+    assert proc.returncode == 2
+    assert proc.stdout.strip() == ""
+    assert "no TPU" in proc.stderr
+
+
+class _Dev:
+    platform = "tpu"
+    device_kind = "TPU v99 imaginary"
+
+
+def test_run_refuses_a_device_missing_from_the_peaks_table(monkeypatch):
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
+    with pytest.raises(run.SetupError, match="not in"):
+        run.device_info(1, os.path.join(lib.BENCH, "peaks.json"))
+    monkeypatch.setattr(jax, "devices", lambda *a: [])
+    with pytest.raises(run.SetupError, match="needs 1 chips"):
+        run.device_info(1, os.path.join(lib.BENCH, "peaks.json"))
+
+
+def test_run_refuses_a_checkout_without_the_program(tmp_path):
+    root = lib.tiny_bench(tmp_path)
+    with pytest.raises(run.SetupError, match="no program"):
+        run.import_program(root)
+
+
+def test_judge_fails_missing_out_of_limit_and_non_finite_numbers(tmp_path):
+    root = lib.tiny_bench(tmp_path)
+    ctx = run.RunContext(run.find_cell("tiny.build.two_pass", root=root), 1, 0.0, False)
+    sound = {name: 0.0 for name in ctx.cell.limits}
+    ok, checks = run.judge(ctx, {**sound, "lev_tv": 1e-4, "diagnostic": 5.0}.items())
+    assert ok and checks["lev_tv"] == {"value": 1e-4, "limit": 4e-4}
+    assert "diagnostic" not in checks
+    assert not run.judge(ctx, {**sound, "lev_tv": float("nan")}.items())[0]
+    assert not run.judge(ctx, {**sound, "lev_tv": 1e-3}.items())[0]
+    del sound["hull_gap"]
+    assert not run.judge(ctx, sound.items())[0]
