@@ -21,6 +21,7 @@ from typing import Literal
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import mctm as M
 from repro.core.bernstein import DataScaler
@@ -136,18 +137,20 @@ def coreset_from_scoring(
     k_sample = int(np.floor(alpha * k)) if method == "l2-hull" else k
     k_hull = k - k_sample if method == "l2-hull" else 0
     scores = res.scores
-    probs = scores / scores.sum()
-    idx = np.asarray(
-        jax.random.choice(
-            key_draw, n, shape=(k_sample,), replace=True, p=jnp.asarray(probs)
+    with TraceAnnotation("repro.coreset.sample"):
+        probs = scores / scores.sum()
+        idx = np.asarray(
+            jax.random.choice(
+                key_draw, n, shape=(k_sample,), replace=True, p=jnp.asarray(probs)
+            )
         )
-    )
-    w = 1.0 / (k_sample * probs[idx])
+        w = 1.0 / (k_sample * probs[idx])
 
     if method == "l2-hull" and k_hull > 0:
-        hull_pts = exact_hull_points(res, scores, k_hull)
-        idx = np.concatenate([idx, hull_pts])
-        w = np.concatenate([w, np.ones(k_hull)])
+        with TraceAnnotation("repro.coreset.hull_points"):
+            hull_pts = exact_hull_points(res, scores, k_hull)
+            idx = np.concatenate([idx, hull_pts])
+            w = np.concatenate([w, np.ones(k_hull)])
 
     return CoresetResult(idx, w, scores, method, time.perf_counter() - t0)
 

@@ -63,6 +63,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.hull import stable_first_unique
@@ -1553,135 +1554,172 @@ class DistributedScoringEngine:
                     f"staged input has {Y.shape[0]} rows but the layout for "
                     f"n={n} needs {n_pad} (use stage_rows)"
                 )
-            pad = n_pad - n
-            Y_pad = Y
         else:
-            Y = jnp.asarray(Y)
-            n = int(Y.shape[0])
+            n = int(np.shape(Y)[0])
             chunk, cps, n_pad = self._shard_layout(n)
-            pad = n_pad - n
-            # pad with copies of row 0 (valid data — no NaN risk through the
-            # featurizer); masks keep pads out of every statistic
-            if pad:
-                Y_pad = jnp.concatenate(
-                    [Y, jnp.broadcast_to(Y[:1], (pad,) + Y.shape[1:])], axis=0
-                )
-            else:
-                Y_pad = Y
-            Y_pad = self._shard_put(Y_pad)
         if n == 0:
             raise ValueError("cannot score an empty dataset")
-        mask = (jnp.arange(n_pad) < n).astype(jnp.float32)
-        sw = (
-            jnp.sqrt(jnp.asarray(weights, jnp.float32))
-            if weights is not None
-            else jnp.ones((n,), jnp.float32)
-        )
-        swm = jnp.concatenate([sw, jnp.zeros((pad,), jnp.float32)]) if pad else sw
+        pad = n_pad - n
+        # bytes staged: the padded rows (unless pre-staged), the mask and √w
+        staged = 2 * n_pad * 4
+        if n_valid is None:
+            row_dtype = np.dtype(getattr(Y, "dtype", np.float32))
+            staged += n_pad * int(np.prod(np.shape(Y)[1:])) * row_dtype.itemsize
+        with TraceAnnotation("repro.scoring.stage", bytes=staged):
+            if n_valid is not None:
+                Y_pad = Y
+            else:
+                Y = jnp.asarray(Y)
+                # pad with copies of row 0 (valid data — no NaN risk through
+                # the featurizer); masks keep pads out of every statistic
+                if pad:
+                    Y_pad = jnp.concatenate(
+                        [Y, jnp.broadcast_to(Y[:1], (pad,) + Y.shape[1:])], axis=0
+                    )
+                else:
+                    Y_pad = Y
+                Y_pad = self._shard_put(Y_pad)
+            mask = (jnp.arange(n_pad) < n).astype(jnp.float32)
+            sw = (
+                jnp.sqrt(jnp.asarray(weights, jnp.float32))
+                if weights is not None
+                else jnp.ones((n,), jnp.float32)
+            )
+            swm = jnp.concatenate([sw, jnp.zeros((pad,), jnp.float32)]) if pad else sw
 
-        mask = self._shard_put(mask)
-        swm = self._shard_put(swm)
+            mask = self._shard_put(mask)
+            swm = self._shard_put(swm)
         shards = _num_shards(self.mesh, self.axes)
 
-        if isinstance(strat, OnePassSketched):
-            u, G_host, hull_rows = self._score_one_pass(
-                strat, key, Y_pad, swm, mask, n, n_pad, chunk, cps,
-                method, ridge_reg, hull_k, hull_key, hull_dirs=hull_dirs,
-            )
-            return finalize_scoring(
-                n, cps * shards, method, G_host, u, hull_rows, r
-            )
-
-        pass1, pass2 = self._pass_fns(
-            chunk, cps, hull, Y_pad.shape[1:], Y_pad.dtype,
-            strat.gram_dtype,
+        score_fn = (
+            self._score_one_pass
+            if isinstance(strat, OnePassSketched)
+            else self._score_two_pass
         )
-
-        # ---- pass 1 (sharded, chunked): one fused psum of (G, Σp, Σppᵀ)
-        G, s1, s2 = pass1(Y_pad, swm, mask)
-        G_host = host_gather(G)
-
-        # ---- between passes: (Jd)² host algebra, identical to single-host
-        V, inv = projection_from_gram(G_host, method, ridge_reg)
-
-        hull_rows = None
-        if hull:
-            if hull_dirs is not None:
-                dirs = np.asarray(hull_dirs, np.float32)
-            else:
-                dirs = directions_from_moments(
-                    hull_key,
-                    host_gather(s1),
-                    host_gather(s2),
-                    n * r,
-                    hull_k,
-                    self.hull_oversample,
-                )
-            u_pad, gimax, gimin = pass2(Y_pad, swm, mask, V, inv, jnp.asarray(dirs))
-            cand = np.concatenate(
-                [host_gather(gimax), host_gather(gimin)]
-            ).astype(np.int64)
+        u, G_host, cand = score_fn(
+            strat, key, Y_pad, swm, mask, n, n_pad, chunk, cps,
+            method, ridge_reg, hull_k, hull_key, hull_dirs=hull_dirs,
+        )
+        with TraceAnnotation("repro.scoring.finalize"):
             # every distinct candidate row, first-occurrence order — matching
             # the single-host engine (truncation to k points happens at the
             # coreset assembly via exact_hull_points)
-            hull_rows = stable_first_unique(cand)
-        else:
-            u_pad = pass2(Y_pad, swm, V, inv)
+            hull_rows = None if cand is None else stable_first_unique(cand)
+            return finalize_scoring(n, cps * shards, method, G_host, u, hull_rows, r)
 
-        u = host_gather(u_pad)[:n]
-        return finalize_scoring(n, cps * shards, method, G_host, u, hull_rows, r)
+    def _score_two_pass(
+        self, strat, key, Y_pad, swm, mask, n, n_pad, chunk, cps,
+        method, ridge_reg, hull_k, hull_key, hull_dirs=None,
+    ):
+        """The sharded exact two-pass sweep: (u, Gram, hull candidates)."""
+        r = self.rows_per_point
+        hull = hull_k > 0
+        # ---- pass 1 (sharded, chunked): one fused psum of (G, Σp, Σppᵀ)
+        with TraceAnnotation("repro.scoring.pass1"):
+            pass1, pass2 = self._pass_fns(
+                chunk, cps, hull, Y_pad.shape[1:], Y_pad.dtype,
+                strat.gram_dtype,
+            )
+            G, s1, s2 = pass1(Y_pad, swm, mask)
+        with TraceAnnotation("repro.scoring.gather.gram", bytes=G.nbytes):
+            G_host = host_gather(G)
+
+        # ---- between passes: (Jd)² host algebra, identical to single-host
+        with TraceAnnotation("repro.scoring.projection"):
+            V, inv = projection_from_gram(G_host, method, ridge_reg)
+
+        cand = None
+        if hull:
+            if hull_dirs is None:
+                with TraceAnnotation(
+                    "repro.scoring.gather.moments", bytes=s1.nbytes + s2.nbytes
+                ):
+                    s1_host, s2_host = host_gather(s1), host_gather(s2)
+            with TraceAnnotation("repro.scoring.directions"):
+                if hull_dirs is not None:
+                    dirs = np.asarray(hull_dirs, np.float32)
+                else:
+                    dirs = directions_from_moments(
+                        hull_key, s1_host, s2_host, n * r, hull_k,
+                        self.hull_oversample,
+                    )
+            with TraceAnnotation("repro.scoring.pass2"):
+                u_pad, gimax, gimin = pass2(
+                    Y_pad, swm, mask, V, inv, jnp.asarray(dirs)
+                )
+            with TraceAnnotation(
+                "repro.scoring.gather.hull", bytes=gimax.nbytes + gimin.nbytes
+            ):
+                cand = np.concatenate(
+                    [host_gather(gimax), host_gather(gimin)]
+                ).astype(np.int64)
+        else:
+            with TraceAnnotation("repro.scoring.pass2"):
+                u_pad = pass2(Y_pad, swm, V, inv)
+
+        with TraceAnnotation("repro.scoring.gather.scores", bytes=u_pad.nbytes):
+            u = host_gather(u_pad)[:n]
+        return u, G_host, cand
 
     def _score_one_pass(
         self, strat, key, Y_pad, swm, mask, n, n_pad, chunk, cps,
         method, ridge_reg, hull_k, hull_key, hull_dirs=None,
     ):
         """The sharded one-pass sweep: ONE data pass, ONE fused state psum."""
-        r = self.rows_per_point
         hull = hull_k > 0
-        fn, D = self._onepass_fn(
-            chunk, cps, hull, Y_pad.shape[1:], Y_pad.dtype,
-            strat.proj_size, strat.sketch_size,
-        )
-        # the global CountSketch plan — identical draws to the single-host
-        # engine, so the two layouts emit the same estimates; pad entries
-        # carry zero sign (and zero √w) so they cannot touch the sketch
-        rows, signs, omega = strat.begin(n, D, key)
-        pad = n_pad - n
-        if pad:
-            rows = jnp.concatenate([rows, jnp.zeros((pad,), rows.dtype)])
-            signs = jnp.concatenate([signs, jnp.zeros((pad,), signs.dtype)])
-        rows = self._shard_put(rows)
-        signs = self._shard_put(signs)
+        with TraceAnnotation("repro.scoring.plan"):
+            fn, D = self._onepass_fn(
+                chunk, cps, hull, Y_pad.shape[1:], Y_pad.dtype,
+                strat.proj_size, strat.sketch_size,
+            )
+            # the global CountSketch plan — identical draws to the single-host
+            # engine, so the two layouts emit the same estimates; pad entries
+            # carry zero sign (and zero √w) so they cannot touch the sketch
+            rows, signs, omega = strat.begin(n, D, key)
+            pad = n_pad - n
+            if pad:
+                rows = jnp.concatenate([rows, jnp.zeros((pad,), rows.dtype)])
+                signs = jnp.concatenate([signs, jnp.zeros((pad,), signs.dtype)])
+            rows = self._shard_put(rows)
+            signs = self._shard_put(signs)
         extras = ()
         if omega is not None:
             extras = extras + (omega,)
-        dirs1 = None
         if hull:
-            dirs1 = jnp.asarray(
-                hull_dirs
-                if hull_dirs is not None
-                else upfront_directions(
-                    hull_key, self._p_rows_width(chunk, Y_pad),
-                    hull_k, self.hull_oversample,
+            with TraceAnnotation("repro.scoring.directions"):
+                dirs1 = jnp.asarray(
+                    hull_dirs
+                    if hull_dirs is not None
+                    else upfront_directions(
+                        hull_key, self._p_rows_width(chunk, Y_pad),
+                        hull_k, self.hull_oversample,
+                    )
                 )
-            )
             extras = extras + (dirs1,)
 
-        outs = fn(Y_pad, swm, mask, rows, signs, *extras)
+        with TraceAnnotation("repro.scoring.sweep"):
+            outs = fn(Y_pad, swm, mask, rows, signs, *extras)
         z, SX = outs[:2]
-        SX_host = host_gather(SX)
-        SXp = SX_host if omega is None else SX_host @ np.asarray(omega)
-        V, inv = projection_from_gram(SXp.T @ SXp, method, ridge_reg)
-        u = host_gather(_z_leverage_jit(z, V, inv))[:n]
-        hull_rows = None
+        with TraceAnnotation("repro.scoring.gather.sketch", bytes=SX.nbytes):
+            SX_host = host_gather(SX)
+        with TraceAnnotation("repro.scoring.projection"):
+            SXp = SX_host if omega is None else SX_host @ np.asarray(omega)
+            V, inv = projection_from_gram(SXp.T @ SXp, method, ridge_reg)
+            G_host = SX_host.T @ SX_host  # reported Gram: the full sketched Gram
+        with TraceAnnotation("repro.scoring.readoff"):
+            u_dev = _z_leverage_jit(z, V, inv)
+        with TraceAnnotation("repro.scoring.gather.scores", bytes=u_dev.nbytes):
+            u = host_gather(u_dev)[:n]
+        cand = None
         if hull:
             gimax, gimin = outs[2], outs[3]
-            cand = np.concatenate(
-                [host_gather(gimax), host_gather(gimin)]
-            ).astype(np.int64)
-            hull_rows = stable_first_unique(cand)
-        G_host = SX_host.T @ SX_host  # reported Gram: the full sketched Gram
-        return u, G_host, hull_rows
+            with TraceAnnotation(
+                "repro.scoring.gather.hull", bytes=gimax.nbytes + gimin.nbytes
+            ):
+                cand = np.concatenate(
+                    [host_gather(gimax), host_gather(gimin)]
+                ).astype(np.int64)
+        return u, G_host, cand
 
     def _p_rows_width(self, chunk, Y_pad) -> int:
         """Width p of the featurizer's P rows (for the upfront net)."""
@@ -1720,31 +1758,37 @@ def distributed_build_coreset(
     """
     from repro.core.coreset import CoresetResult, coreset_from_scoring
 
-    t0 = time.perf_counter()
-    Y = np.asarray(Y)
-    n = Y.shape[0]
-    k = min(k, n)
+    with TraceAnnotation("repro.build"):
+        t0 = time.perf_counter()
+        Y = np.asarray(Y)
+        n = Y.shape[0]
+        k = min(k, n)
 
-    if method == "uniform":
-        idx = np.asarray(jax.random.choice(key, n, shape=(k,), replace=False))
-        w = np.full(k, n / k)
-        return CoresetResult(idx, w, None, method, time.perf_counter() - t0)
+        if method == "uniform":
+            idx = np.asarray(jax.random.choice(key, n, shape=(k,), replace=False))
+            w = np.full(k, n / k)
+            return CoresetResult(idx, w, None, method, time.perf_counter() - t0)
 
-    # same 3-way split as build_coreset (k_score feeds the sketch plan) so
-    # the two paths draw identical samples when their scores agree
-    k_score, k_hull_key, k_draw = jax.random.split(key, 3)
-    k_hull = k - int(np.floor(alpha * k)) if method == "l2-hull" else 0
-    engine = DistributedScoringEngine(
-        cfg, scaler, mesh=mesh, axis=axis, chunk_size=chunk_size
-    )
-    res = engine.score(
-        Y if sweep_ckpt is not None else jnp.asarray(Y),
-        method=method,
-        hull_k=k_hull,
-        hull_key=k_hull_key,
-        sketch_size=sketch_size,
-        key=k_score if sketch_size > 0 else None,
-        sweep_ckpt=sweep_ckpt,
-        resume=resume,
-    )
-    return coreset_from_scoring(res, n, k, method, alpha, k_draw, t0)
+        # same 3-way split as build_coreset (k_score feeds the sketch plan) so
+        # the two paths draw identical samples when their scores agree
+        k_score, k_hull_key, k_draw = jax.random.split(key, 3)
+        k_hull = k - int(np.floor(alpha * k)) if method == "l2-hull" else 0
+        with TraceAnnotation("repro.build.engine"):
+            engine = DistributedScoringEngine(
+                cfg, scaler, mesh=mesh, axis=axis, chunk_size=chunk_size
+            )
+        rows = Y
+        if sweep_ckpt is None:
+            with TraceAnnotation("repro.build.put_rows", bytes=Y.nbytes):
+                rows = jnp.asarray(Y)
+        res = engine.score(
+            rows,
+            method=method,
+            hull_k=k_hull,
+            hull_key=k_hull_key,
+            sketch_size=sketch_size,
+            key=k_score if sketch_size > 0 else None,
+            sweep_ckpt=sweep_ckpt,
+            resume=resume,
+        )
+        return coreset_from_scoring(res, n, k, method, alpha, k_draw, t0)

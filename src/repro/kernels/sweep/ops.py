@@ -15,6 +15,8 @@ like the f64 Gram carry bypasses the Pallas gram kernel.
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 
@@ -35,6 +37,9 @@ def _pad_to(x, rows: int, cols: int):
     return out.at[: x.shape[0], : x.shape[1]].set(x.astype(jnp.float32))
 
 
+# jitted so the kernel's custom call is named after this wrapper
+# (``%_sweep_pallas.N``), as the gram and extremes kernels are
+@partial(jax.jit, static_argnames=("want_z", "block_rows", "interpret"))
 def _sweep_pallas(
     SX, X, P, sw, rows, signs, n_valid, dirs, omega, moments,
     *, want_z: bool, block_rows: int, interpret: bool,

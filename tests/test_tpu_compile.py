@@ -139,3 +139,45 @@ def test_fused_sweep_ops_wrapper_compiles(one_chip, rows):
     )
     _compile_and_check(fn, *args, dirs=_sds((M_DIRS, d), f32, one_chip),
                        mask=_sds((rows,), f32, one_chip))
+
+
+def test_one_pass_program_names_the_sweep_kernel(one_chip, monkeypatch):
+    """The sharded one-pass program, compiled with the Pallas sweep as on the
+    chip: the kernel's custom call is named after its jitted wrapper
+    (``%_sweep_pallas.N``), and the benchmark's matcher counts it as the
+    sweep kernel."""
+    import os
+    import sys
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import repro.kernels.sweep.ops as sweep_ops
+    from repro.core import mctm as M
+    from repro.core.bernstein import DataScaler
+    from repro.core.distributed_coreset import make_sharded_onepass_fn
+    from repro.core.scoring import _mctm_featurize
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from chipbench import roofline
+
+    # the backend the chip would pick (``default_sweep_backend()`` sees the CPU)
+    monkeypatch.setattr(sweep_ops, "default_sweep_backend", lambda: "pallas")
+    Y = np.random.default_rng(0).standard_normal((100, J)).astype(np.float32)
+    featurize = _mctm_featurize(M.MCTMConfig(J=J, degree=DEGREE), DataScaler.fit(Y))
+    mesh = Mesh(np.array(list(one_chip.device_set)), ("data",))
+    cps = 2
+    fn = make_sharded_onepass_fn(featurize, mesh, ("data",), chunk=CHUNK,
+                                 chunks_per_shard=cps, rows_per_point=J, hull=True,
+                                 D=D, q=None, sketch_size=SKETCH)
+    n = CHUNK * cps
+    row, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    args = (_sds((n, J), jnp.float32, NamedSharding(mesh, P("data", None))),
+            _sds((n,), jnp.float32, row), _sds((n,), jnp.float32, row),
+            _sds((n,), jnp.int32, row), _sds((n,), jnp.float32, row),
+            _sds((M_DIRS, d), jnp.float32, rep))
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [ln.strip() for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    sweeps = [c for c in calls if c.startswith("%_sweep_pallas")]
+    assert sweeps, calls
+    assert all(roofline.is_kernel("sweep", c) for c in sweeps)
