@@ -266,9 +266,10 @@ def leverage_chunk(X, sw, V, inv):
 def hull_chunk_extremes(P, dirs, mask=None):
     """Per-chunk directional extremes: (max, argmax, min, argmin) per direction.
 
-    Backend-dispatched like ``gram_matrix``: the fused Pallas running-extreme
-    kernel on TPU (the (m, c·r) score block never leaves VMEM), the jnp
-    oracle elsewhere (``kernels.extremes``). ``mask`` (c·r,) excludes padding
+    Backend-dispatched like ``gram_matrix``: the fused Pallas kernel on TPU
+    (each (m, block_rows) score tile is folded lane-wise into (m, 128)
+    running-extreme accumulators in VMEM, reduced across lanes once per
+    call), the jnp oracle elsewhere (``kernels.extremes``). ``mask`` (c·r,) excludes padding
     rows (sharded inputs padded to a shard multiple) by sending their scores
     to ∓inf. Pure — both the two-pass and one-pass scan bodies (single-host
     and sharded) fold this into their running extremes.

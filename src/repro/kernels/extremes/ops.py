@@ -17,7 +17,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.extremes.kernel import DEFAULT_BLOCK_ROWS, LANE, extremes_kernel
+from repro.kernels.extremes.kernel import EXTREMES_BLOCK_ROWS, LANE, extremes_kernel
 from repro.kernels.extremes.ref import directional_extremes_ref
 
 
@@ -34,10 +34,11 @@ def _pad_to(x: jax.Array, rows: int, cols: int) -> jax.Array:
 @partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def _extremes_pallas(P, dirs, n_valid, *, block_rows: int, interpret: bool):
     """Pads rows/lanes (pad rows are masked by the n_valid count, pad lanes
-    contribute zero to the scores, pad directions are sliced off)."""
+    contribute zero to the scores, pad directions are sliced off). The row
+    tile is a multiple of LANE, clamped to the lane-padded row count."""
     n, d = P.shape
     m = dirs.shape[0]
-    block_rows = min(block_rows, -(-n // 8) * 8)
+    block_rows = min(-(-block_rows // LANE), -(-n // LANE)) * LANE
     n_pad = -(-n // block_rows) * block_rows
     d_pad = -(-d // LANE) * LANE
     m_pad = -(-m // LANE) * LANE
@@ -57,7 +58,7 @@ def directional_extremes(
     dirs: jax.Array,
     mask: jax.Array | None = None,
     *,
-    block_rows: int = DEFAULT_BLOCK_ROWS,
+    block_rows: int = EXTREMES_BLOCK_ROWS,
     backend: str | None = None,
     interpret: bool | None = None,
 ):

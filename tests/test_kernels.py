@@ -59,20 +59,58 @@ def test_gram_kernel_sweep(shape, dtype):
 # ----------------------------------------------------------- extremes
 
 
-@pytest.mark.parametrize("n,m,d", [(64, 8, 5), (777, 24, 7), (1024, 130, 14)])
-def test_extremes_kernel_sweep(n, m, d):
+@pytest.mark.parametrize(
+    "n,m,d,block_rows,n_valid,ties",
+    [
+        pytest.param(64, 8, 5, None, None, False, id="64-8-5"),
+        pytest.param(777, 24, 7, None, None, False, id="777-24-7"),
+        pytest.param(1024, 130, 14, None, None, False, id="1024-130-14"),
+        # eight grid steps over a row count that is no multiple of 128
+        pytest.param(1000, 24, 7, 128, None, False, id="steps"),
+        # tied copies in other grid steps and lane positions (below)
+        pytest.param(1152, 40, 7, 128, None, True, id="ties"),
+        pytest.param(1152, 40, 7, 128, 1030, True, id="ties-ragged"),
+        # a ragged tail whose masked rows would win if they counted
+        pytest.param(1000, 24, 7, 256, 777, False, id="ragged"),
+        pytest.param(300, 16, 6, 128, 0, False, id="all-masked"),
+    ],
+)
+def test_extremes_kernel_sweep(n, m, d, block_rows, n_valid, ties):
+    """The kernel against the dense-argmax oracle: indices bit for bit
+    (lowest row among equal values; ∓inf and index 0 with no valid row),
+    values to 1e-4."""
     from repro.kernels.extremes.ops import directional_extremes
     from repro.kernels.extremes.ref import directional_extremes_ref
 
     rng = np.random.default_rng(n + m)
-    P = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
+    P_np = rng.standard_normal((n, d)).astype(np.float32)
+    if ties:
+        # 50 dominant rows, copied at rows 140, 396 (the same lanes, two steps
+        # later) and 1000 (lanes 104.., and 0.. past 1024): every extreme is
+        # a three-way tie that the first copy must win, though the last
+        # copy's rows from 1024 on sit in lower lanes
+        top = 3.0 * P_np[:50]
+        for lo in (140, 396, 1000):
+            P_np[lo:lo + 50] = top
+    mask = None
+    if n_valid is not None:
+        P_np[n_valid:] *= 10.0
+        mask = jnp.arange(n) < n_valid
+    P = jnp.asarray(P_np)
     dirs = jnp.asarray(rng.standard_normal((m, d)), jnp.float32)
-    got = directional_extremes(P, dirs, interpret=True)
-    ref = directional_extremes_ref(P, dirs)
-    for g, r in zip(got, ref):
-        np.testing.assert_allclose(
-            np.asarray(g, np.float64), np.asarray(r, np.float64), atol=1e-4
-        )
+    kw = {} if block_rows is None else {"block_rows": block_rows}
+    vmax, imax, vmin, imin = directional_extremes(P, dirs, mask, interpret=True, **kw)
+    rvmax, rimax, rvmin, rimin = directional_extremes_ref(P, dirs, mask)
+    np.testing.assert_array_equal(np.asarray(imax), np.asarray(rimax))
+    np.testing.assert_array_equal(np.asarray(imin), np.asarray(rimin))
+    np.testing.assert_allclose(np.asarray(vmax), np.asarray(rvmax), atol=1e-4)
+    np.testing.assert_allclose(np.asarray(vmin), np.asarray(rvmin), atol=1e-4)
+    if ties:
+        for idx in (imax, imin):
+            assert np.all((np.asarray(idx) >= 140) & (np.asarray(idx) < 190))
+    if n_valid == 0:
+        assert np.all(np.isneginf(np.asarray(vmax))) and not np.any(np.asarray(imax))
+        assert np.all(np.isposinf(np.asarray(vmin))) and not np.any(np.asarray(imin))
 
 
 def test_extremes_kernel_mask_and_ties():

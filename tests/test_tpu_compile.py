@@ -20,7 +20,11 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.bernstein.kernel import bernstein_kernel
-from repro.kernels.extremes.kernel import DEFAULT_BLOCK_ROWS, extremes_kernel
+from repro.kernels.extremes.kernel import (
+    DEFAULT_BLOCK_ROWS,
+    EXTREMES_BLOCK_ROWS,
+    extremes_kernel,
+)
 from repro.kernels.gram.kernel import gram_kernel
 from repro.kernels.sweep.kernel import sweep_kernel
 from repro.kernels.sweep.ops import fused_sweep_update
@@ -98,13 +102,16 @@ def test_sweep_kernel_moments_compiles(one_chip):
     _compile_and_check(fn, *_sweep_operands(one_chip, hull=False))
 
 
-def test_extremes_kernel_compiles(one_chip):
-    """The two-pass pass-2 hull reduction over one chunk's derivative rows."""
+@pytest.mark.parametrize("rows", [CHUNK * J, CHUNK * 10])
+def test_extremes_kernel_compiles(one_chip, rows):
+    """The two-pass pass-2 hull reduction over one chunk's derivative rows,
+    at J=2 and J=10: the score tile and the four (M_PAD, 128) VMEM
+    accumulators fit the chip's scoped VMEM."""
     f32 = jnp.float32
-    fn = partial(extremes_kernel, block_rows=DEFAULT_BLOCK_ROWS)
+    fn = partial(extremes_kernel, block_rows=EXTREMES_BLOCK_ROWS)
     _compile_and_check(
         fn,
-        _sds((CHUNK * J, LANE), f32, one_chip),
+        _sds((rows, LANE), f32, one_chip),
         _sds((M_PAD, LANE), f32, one_chip),
         _sds((1, 1), jnp.int32, one_chip),
     )
