@@ -200,3 +200,85 @@ def hull_gap(ref: HullReference, dirs: np.ndarray, Y_hull: np.ndarray) -> float:
         got = float(ref.values(Y_hull, v).max())
         worst = max(worst, (hi - got) / max(hi - lo, 1e-300))
     return worst
+
+
+# ---------------------------------------------------------------------------
+# the MCTM objective: weighted negative log-likelihood, value and gradient
+# ---------------------------------------------------------------------------
+
+LOG_2PI = float(np.log(2.0 * np.pi))
+
+
+def monotone_theta(theta_raw: np.ndarray, min_slope: float) -> np.ndarray:
+    """Strictly increasing coefficients from unconstrained ones: θ_0 = raw_0,
+    θ_k = θ_(k−1) + softplus(raw_k) + min_slope."""
+    raw = np.asarray(theta_raw, np.float64)
+    steps = np.logaddexp(0.0, raw[:, 1:]) + min_slope
+    return np.concatenate([raw[:, :1], raw[:, :1] + np.cumsum(steps, axis=1)], axis=1)
+
+
+class MCTMObjective:
+    """Σ_i w_i·nll_i(ϑ, λ) of an MCTM on weighted rows, in float64.
+
+    nll_i = Σ_j ½·z_ij² − log max(h̃′_ij, η) + ½·log 2π, with h̃_ij = a(y_ij)ᵀϑ_j,
+    h̃′_ij = a′(y_ij)ᵀϑ_j, ϑ = monotone(raw) and z_i = Λ·h̃_i for the unit
+    lower-triangular Λ whose strict lower part holds λ in row-major order.
+    The gradient is written out, so nothing here is differentiated by a tool.
+    """
+
+    def __init__(self, Y, w, low, high, degree: int, eta: float, min_slope: float):
+        self.A, self.dA = host_features(Y, low, high, degree)
+        self.w = np.asarray(w, np.float64)
+        self.J, self.d = self.A.shape[1], self.A.shape[2]
+        self.eta, self.min_slope = float(eta), float(min_slope)
+        self.rows, self.cols = np.tril_indices(self.J, k=-1)
+
+    def unpack(self, x: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+        n_theta = self.J * self.d
+        return x[:n_theta].reshape(self.J, self.d), x[n_theta:]
+
+    def value_and_grad(self, theta_raw, lam) -> tuple[float, np.ndarray, np.ndarray]:
+        """(total, ∂/∂raw, ∂/∂λ) of the weighted sum."""
+        raw = np.asarray(theta_raw, np.float64)
+        theta = monotone_theta(raw, self.min_slope)
+        h = np.einsum("njd,jd->nj", self.A, theta)
+        hp = np.einsum("njd,jd->nj", self.dA, theta)
+        L = np.eye(self.J)
+        L[self.rows, self.cols] = lam
+        z = h @ L.T
+        live = hp > self.eta
+        terms = np.sum(0.5 * z * z - np.log(np.maximum(hp, self.eta)) + 0.5 * LOG_2PI, axis=1)
+        gz = self.w[:, None] * z
+        g_hp = np.where(live, -self.w[:, None] / np.where(live, hp, 1.0), 0.0)
+        g_theta = (np.einsum("nj,njd->jd", gz @ L, self.A)
+                   + np.einsum("nj,njd->jd", g_hp, self.dA))
+        # θ_k depends on raw_0 and on raw_m for 1 ≤ m ≤ k, the latter through
+        # softplus, whose derivative is the logistic function
+        g_raw = np.cumsum(g_theta[:, ::-1], axis=1)[:, ::-1]
+        g_raw[:, 1:] *= np.exp(-np.logaddexp(0.0, -raw[:, 1:]))
+        g_lam = (gz.T @ h)[self.rows, self.cols]
+        return float(self.w @ terms), g_raw, g_lam
+
+    def value(self, theta_raw, lam) -> float:
+        return self.value_and_grad(theta_raw, lam)[0]
+
+
+def polish(obj: MCTMObjective, theta_raw, lam, maxiter: int = 1000):
+    """Minimise the objective in float64 with scipy's L-BFGS-B from the given
+    parameters. The objective is normalised as the program's L-BFGS fit
+    normalises it, by the total weight Σw, so the tolerances read on the
+    same scale. Returns (raw, λ, total at the end)."""
+    from scipy.optimize import minimize
+
+    norm = float(obj.w.sum())
+
+    def fun(x):
+        v, g_raw, g_lam = obj.value_and_grad(*obj.unpack(x))
+        return v / norm, np.concatenate([g_raw.ravel(), g_lam]) / norm
+
+    x0 = np.concatenate([np.ravel(np.asarray(theta_raw, np.float64)),
+                         np.asarray(lam, np.float64)])
+    res = minimize(fun, x0, jac=True, method="L-BFGS-B",
+                   options={"maxiter": maxiter, "maxcor": 20, "ftol": 0.0, "gtol": 1e-12})
+    raw, lam = obj.unpack(res.x)
+    return raw, lam, obj.value(raw, lam)

@@ -10,8 +10,8 @@ from __future__ import annotations
 
 # A kernel shows in the trace as a ``tpu_custom_call`` HLO instruction named
 # after the jitted wrapper that called ``pallas_call``. The sweep kernel's
-# wrapper is inlined and its instruction is a ``closed_call``: it is the
-# custom call that no named kernel claims.
+# instruction is ``%_sweep_pallas.N``; it has no entry here and is matched as
+# the custom call that no named kernel claims.
 NAMED = {"gram": "%_gram_pallas", "extremes": "%_extremes_pallas",
          "bernstein": "%_bernstein_pallas"}
 

@@ -14,16 +14,28 @@ for _p in (ROOT, os.path.join(ROOT, "src")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
+from chipbench import datagen  # noqa: E402
+
 # tiny sizes: a few seconds a run on the CPU
 TINY = {
     "tiny_j2": ("mctm_j2_mixture", {"n": 20001, "k": 200, "chunk": 4096}),
+    "tiny_j3": ("mctm_j10_covertype",
+                {"J": 3, "degree": 4, "n": 4097, "k": 200, "chunk": 4096, "dgp": "covertype_j3"}),
 }
-# (workload, config, traffic, limits), limits read off CPU runs at these sizes
+# a three-column generator for the tiny fit: the first three Covertype columns
+datagen.GENERATORS.setdefault("covertype_j3", lambda rng, n: datagen.covertype(rng, n)[:, :3])
+# (workload, config, traffic, limits, the cell whose metrics it reports),
+# limits read off CPU runs at these sizes
 TINY_CELLS = [
     ("tiny.build.two_pass", "tiny_j2", "tiny_two_pass",
-     {"lev_tv": 4e-4, "w_dev": 6e-4, "draw_gap": 1e-2, "hull_gap": 4e-7, "shape_err": 0.0}),
+     {"lev_tv": 4e-4, "w_dev": 6e-4, "draw_gap": 1e-2, "hull_gap": 4e-7, "shape_err": 0.0},
+     "build.j2_mixture.two_pass"),
     ("tiny.build.one_pass", "tiny_j2", "build_one_pass",
-     {"lev_tv": 1.5e-3, "w_dev": 2e-3, "draw_gap": 3e-2, "hull_gap": 4e-7, "shape_err": 0.0}),
+     {"lev_tv": 1.5e-3, "w_dev": 2e-3, "draw_gap": 3e-2, "hull_gap": 4e-7, "shape_err": 0.0},
+     "build.j2_mixture.one_pass"),
+    ("tiny.fit.lbfgs", "tiny_j3", "tiny_fit_lbfgs",
+     {"obj_dev": 1e-6, "fit_gap": 2e-4, "shape_err": 0.0},
+     "fit.j10_covertype.lbfgs"),
 ]
 
 
@@ -53,12 +65,17 @@ def tiny_bench(tmp_path) -> str:
         mix = json.load(f)
     mix.update(check_calls=1)
     _dump(os.path.join(bench_dir, "traffic", "tiny_two_pass.json"), mix)
-    for wl, cfg, traffic, limits in TINY_CELLS:
+    # the fit mix at a test's length: fewer steps, two coresets, one checked
+    with open(os.path.join(bench_dir, "traffic", "fit_lbfgs.json")) as f:
+        mix = json.load(f)
+    mix.update(steps=60, coresets=2, check_calls=1)
+    _dump(os.path.join(bench_dir, "traffic", "tiny_fit_lbfgs.json"), mix)
+    for wl, cfg, traffic, limits, like in TINY_CELLS:
         bench["workloads"].append({"name": wl, "config": cfg, "traffic": traffic,
                                    "chips": 1, "why": "test"})
         _dump(os.path.join(bench_dir, "limits", wl + ".json"), limits)
         for m in bench["end_to_end"] + bench["per_layer"]:
-            if "workloads" in m:
+            if like in m.get("workloads", ()):
                 m["workloads"].append(wl)
     _dump(os.path.join(root, "BENCHMARK.json"), bench)
     return root

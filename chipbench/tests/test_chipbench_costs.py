@@ -72,3 +72,25 @@ def test_whole_build_by_hand():
     ten = (8 * 10 * N10 * 7 * 2 + 2 * N10 * 4900 + (2 * N10 * 4900 + 2 * N10 * 70)
            + 2 * 10 * N10 * 7 * 1614)
     assert b.flops(cfg10, TWO) == ten
+
+
+FIT = {"steps": 400}
+
+
+def test_whole_fit_by_hand():
+    """Per step one value-and-gradient and one Hessian-vector sweep over the
+    k = 2000 coreset rows: basis 16·k·J·d each; value-and-gradient 8·k·J·d +
+    6·k·J² of products; the HVP adds 8·k·J·d + 12·k·J² of tangents."""
+    f = _cost("fit")
+    # J=2, d=7: k·J·d = 28,000, k·J² = 8,000
+    vg2 = 24 * 28_000 + 6 * 8_000
+    hvp2 = vg2 + 8 * 28_000 + 12 * 8_000
+    assert (vg2, hvp2) == (720_000, 1_040_000)
+    assert f.flops(_cfg("mctm_j2_mixture"), FIT) == 400 * 1_760_000
+    # J=10, d=7: k·J·d = 140,000, k·J² = 200,000
+    vg10 = 24 * 140_000 + 6 * 200_000
+    hvp10 = vg10 + 8 * 140_000 + 12 * 200_000
+    assert (vg10, hvp10) == (4_560_000, 8_080_000)
+    assert f.flops(_cfg("mctm_j10_covertype"), FIT) == 400 * 12_640_000
+    # ≈ 5.06 GFLOP a fit: 25.7 µs at 197 TFLOP/s
+    assert f.flops(_cfg("mctm_j10_covertype"), FIT) / 197e12 == pytest.approx(2.57e-5, rel=0.01)

@@ -104,3 +104,49 @@ def test_judge_fails_missing_out_of_limit_and_non_finite_numbers(tmp_path):
     assert not run.judge(ctx, {**sound, "lev_tv": 1e-3}.items())[0]
     del sound["hull_gap"]
     assert not run.judge(ctx, sound.items())[0]
+
+
+def test_build_and_fit_metrics_stay_in_their_own_cells():
+    for wl in _bench()["workloads"]:
+        cell = run.find_cell(wl["name"])
+        names = {m["name"] for m in cell.end_to_end + cell.per_layer}
+        kind = cell.traffic["entry"]
+        # a metric named for a kind of call applies only to cells of that kind
+        suffixed = [n.rsplit(".", 1)[1] for n in names if "." in n]
+        assert all(s == kind for s in suffixed), (wl["name"], names)
+        if kind == "fit":
+            assert {m["name"] for m in cell.end_to_end} == {"fit_s", "setup_s"}
+            assert {n for n in names if n.endswith(".fit")} == {
+                "idle_share.fit", "lowerings.fit", "compiles.fit", "sweeps.fit",
+                "sweep_ms.fit", "mfu.fit"}
+        else:
+            assert "fit_s" not in names
+
+
+def _fit_context(window_s: float):
+    """A fit cell's context with three completed fits in a window."""
+    cell = run.find_cell("fit.j10_covertype.lbfgs")
+    ctx = run.RunContext(cell, 1, 10.0, True, peaks={"flops_per_s": 197e12})
+    ctx.results = [{"sweeps": {"vg": vg, "hvp": 400, "iters": 400}}
+                   for vg in (450, 480, 510)]
+    ctx.window_s = window_s
+    return ctx
+
+
+def test_fit_readers_on_a_stand_in_context():
+    ctx = _fit_context(21.6)
+    read = {m: run.metric_reader(ctx.cell, m).read(ctx)
+            for m in ("fit_s", "sweeps.fit", "sweep_ms.fit", "mfu.fit")}
+    assert read["fit_s"] == pytest.approx(7.2)
+    # (850 + 880 + 910) sweeps over 3 fits, and the window over all of them
+    assert read["sweeps.fit"] == pytest.approx(880.0)
+    assert read["sweep_ms.fit"] == pytest.approx(21600.0 / 2640)
+    # 3 fits × 400 × 12.64 MFLOP over 21.6 s × 197 TFLOP/s
+    assert read["mfu.fit"] == pytest.approx(100 * 3 * 400 * 12.64e6 / (21.6 * 197e12))
+    # no fit, nothing to read; no peaks, no share of one
+    ctx.results = []
+    assert run.metric_reader(ctx.cell, "sweeps.fit").read(ctx) is None
+    assert run.metric_reader(ctx.cell, "sweep_ms.fit").read(ctx) is None
+    ctx = _fit_context(21.6)
+    ctx.peaks = None
+    assert run.metric_reader(ctx.cell, "mfu.fit").read(ctx) is None

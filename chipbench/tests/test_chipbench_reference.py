@@ -76,3 +76,51 @@ def test_hull_gap_is_zero_for_the_true_extremes(data):
     best = np.unique([np.argmax((dA @ v).max(axis=1)) for v in dirs])
     assert R.hull_gap(ref, dirs, Y[best]) == pytest.approx(0.0, abs=1e-12)
     assert R.hull_gap(ref, dirs, Y[:5]) > 1e-3
+
+
+def test_fit_objective_matches_the_program_in_float64(data):
+    """The written-out objective and gradient against the program's
+    ``mctm.nll`` and autodiff, normalised as ``method_batch_plan`` normalises
+    the L-BFGS fit, all in float64."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import mctm as M
+    from repro.core.bernstein import DataScaler
+    from repro.core.mctm_fit import method_batch_plan
+
+    Y, low, high = data
+    Y = Y[:500]
+    rng = np.random.default_rng(3)
+    w = rng.uniform(0.5, 3.0, Y.shape[0]).astype(np.float32)
+    raw, lam = rng.normal(size=(3, 6)), 0.3 * rng.normal(size=3)
+    ref = R.MCTMObjective(Y, w, low, high, 5, 1e-3, 1e-4)
+    v, g_raw, g_lam = ref.value_and_grad(raw, lam)
+    with jax.enable_x64(True):
+        cfg = M.MCTMConfig(J=3, degree=5, eta=1e-3, min_slope=1e-4)
+        A, Ap = M.basis_features(cfg, DataScaler(low=low, high=high),
+                                 jnp.asarray(Y, jnp.float64))
+        norm = method_batch_plan("lbfgs", Y.shape[0], w, 16384, None)[-1]
+
+        def objective(p):
+            return M.nll(cfg, p, A, Ap, jnp.asarray(w, jnp.float64)) / norm
+
+        pv, pg = jax.value_and_grad(objective)(M.MCTMParams(jnp.asarray(raw), jnp.asarray(lam)))
+    assert norm == pytest.approx(float(w.astype(np.float64).sum()), rel=1e-6)
+    assert float(pv) * norm == pytest.approx(v, rel=1e-12)
+    np.testing.assert_allclose(np.asarray(pg.theta_raw) * norm, g_raw, rtol=1e-10, atol=1e-10)
+    np.testing.assert_allclose(np.asarray(pg.lam) * norm, g_lam, rtol=1e-10, atol=1e-10)
+
+
+def test_polish_goes_down_to_a_stationary_point(data):
+    Y, low, high = data
+    Y = Y[:500]
+    w = np.random.default_rng(4).uniform(0.5, 3.0, Y.shape[0])
+    ref = R.MCTMObjective(Y, w, low, high, 4, 1e-3, 1e-4)
+    raw = np.tile(np.linspace(-2.0, 2.0, 5), (3, 1))
+    lam = np.zeros(3)
+    start = ref.value(raw, lam)
+    raw2, lam2, end = R.polish(ref, raw, lam, maxiter=3000)
+    assert end == ref.value(raw2, lam2) and end < start
+    # a second polish from there finds next to nothing more
+    assert (end - R.polish(ref, raw2, lam2)[2]) / abs(end) < 1e-7
