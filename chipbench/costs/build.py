@@ -5,8 +5,8 @@ directional extremes."""
 from chipbench.costs.shapes import build_shapes
 
 
-def flops(cfg: dict, traffic: dict) -> float:
-    s = build_shapes(cfg, traffic)
+def flops(cfg: dict, traffic: dict, model) -> float:
+    s = build_shapes(cfg, traffic, model)
     n, D, rows, d, m = s["n"], s["D"], s["rows"], s["d"], s["m"]
     basis = 8.0 * rows * d * (1 if s["sketch"] else 2)
     accumulate = 3.0 * n * D if s["sketch"] else 2.0 * n * D * D

@@ -2,11 +2,11 @@
 from chipbench.costs.shapes import build_shapes
 
 
-def flops(cfg: dict, traffic: dict) -> float:
-    s = build_shapes(cfg, traffic)
+def flops(cfg: dict, traffic: dict, model) -> float:
+    s = build_shapes(cfg, traffic, model)
     return 2.0 * s["n"] * s["D"] ** 2
 
 
-def bytes(cfg: dict, traffic: dict) -> float:
-    s = build_shapes(cfg, traffic)
+def bytes(cfg: dict, traffic: dict, model) -> float:
+    s = build_shapes(cfg, traffic, model)
     return 4.0 * (s["n"] * s["D"] + s["chunks"] * s["D"] ** 2)

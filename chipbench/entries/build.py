@@ -1,9 +1,12 @@
-"""Closed loop of coreset builds: ``distributed_build_coreset`` back to back.
+"""Closed loop of coreset builds: the model's build call back to back.
 
 Each call is one whole user call: float32 host rows in, ``CoresetResult``
-out, on a one-device data mesh, with its own key from the seed. The
-traffic file sets the strategy: ``sketch_factor`` 0 is the exact two-pass
-build, f > 0 the one-pass sketched build with sketch f·D².
+out, on the cell's data mesh, with its own key from the seed. The
+configuration's model (``chipbench/models/<model>.py``) makes the program
+objects, the call and the float64 reference; this loop, its keys and the
+check are every build cell's. The traffic file sets the strategy:
+``sketch_factor`` 0 is the exact two-pass build, f > 0 the one-pass
+sketched build with sketch f·D².
 
 The check compares, for builds drawn from the seed, everything a build
 returns against the float64 reference: the leverage part of the scores
@@ -20,7 +23,7 @@ import numpy as np
 
 from chipbench import datagen
 from chipbench import reference as R
-from chipbench.costs.shapes import HULL_OVERSAMPLE
+from chipbench.costs.shapes import HULL_OVERSAMPLE, build_shapes
 
 NAME = "build"
 # directions of the hull net whose extreme the returned hull must hold: the
@@ -30,29 +33,19 @@ NAME = "build"
 CHECK_DIRS = 128
 
 
-def sketch_size(cfg: dict, traffic: dict) -> int:
-    D = cfg["J"] * (cfg["degree"] + 1)
-    return int(traffic.get("sketch_factor", 0)) * D * D
-
-
 def setup(ctx) -> dict:
     import jax
 
-    from repro.core import mctm as M
-    from repro.core.bernstein import DataScaler
-    from repro.utils.compat import make_mesh
-
-    cfg, traffic = ctx.config, ctx.traffic
-    Y = datagen.generate(cfg["dgp"], cfg["n"], ctx.seed, cfg["data_seed"])
+    cfg, model = ctx.config, ctx.cell.model
+    Y = datagen.generate(cfg["dgp"], cfg["n"], ctx.seed, cfg["data_seed"],
+                         bench_dir=ctx.cell.bench_dir)
     ctx.phase("data")
     state = {
         "Y": Y,
-        "model": M.MCTMConfig(J=cfg["J"], degree=cfg["degree"], eta=cfg["eta"],
-                          min_slope=cfg["min_slope"]),
-        "scaler": DataScaler.fit(Y),
-        "mesh": make_mesh((1,), ("data",), devices=jax.devices()[:1]),
+        "program": model.program(cfg, Y),
+        "mesh": ctx.cell.data_mesh(),
         "base_key": jax.random.PRNGKey(ctx.seed),
-        "sketch": sketch_size(cfg, traffic),
+        "sketch": build_shapes(cfg, ctx.traffic, model)["sketch"],
     }
     # one whole call warms every shape the window uses; its key is never
     # drawn in the window
@@ -68,14 +61,8 @@ def key_for(state, i: int):
 
 
 def call(ctx, state, i: int) -> dict:
-    from repro.core import distributed_coreset
-
-    cfg = ctx.config
-    cs = distributed_coreset.distributed_build_coreset(
-        state["model"], state["scaler"], state["Y"], cfg["k"], cfg["method"],
-        mesh=state["mesh"], key=key_for(state, i), alpha=cfg["alpha"],
-        chunk_size=cfg["chunk"], sketch_size=state["sketch"],
-    )
+    cs = ctx.cell.model.build(ctx.config, state["program"], state["Y"], mesh=state["mesh"],
+                              key=key_for(state, i), sketch_size=state["sketch"])
     return {"i": i, "indices": np.asarray(cs.indices), "weights": np.asarray(cs.weights),
             "scores": np.asarray(cs.scores)}
 
@@ -84,24 +71,14 @@ def work(ctx, result) -> dict:
     return {"rows": ctx.config["n"]}
 
 
-def control_inputs(ctx, state) -> dict:
-    cfg = ctx.config
-    low, high = datagen.scaler_bounds(state["Y"].astype(np.float64))
-    return {"Y": state["Y"], "low": low, "high": high, "degree": cfg["degree"],
-            "k": cfg["k"], "alpha": cfg["alpha"], "chunk": cfg["chunk"],
-            "sketch_size": state["sketch"]}
-
-
 def control_call(ctx, state, i: int) -> dict:
-    from chipbench import control
-
-    out = control.build(control_inputs(ctx, state), key_for(state, i))
+    out = ctx.cell.model.control(ctx.config, state["Y"], key_for(state, i), state["sketch"])
     out["i"] = i
     return out
 
 
 def release(state) -> None:
-    for k in ("model", "scaler", "mesh"):
+    for k in ("program", "mesh"):
         state.pop(k, None)
 
 
@@ -155,15 +132,14 @@ def check(ctx, state, results, rng) -> list[tuple[str, float]]:
 
     cfg = ctx.config
     Y = state["Y"]
-    n, J = Y.shape
-    low, high = datagen.scaler_bounds(Y.astype(np.float64))
-    deg, k, sketch = cfg["degree"], cfg["k"], state["sketch"]
+    n = Y.shape[0]
+    d = ctx.cell.model.widths(cfg)["d"]
+    k, sketch = cfg["k"], state["sketch"]
     k_sample = int(np.floor(cfg["alpha"] * k))
     k_hull = k - k_sample
     n_check = min(len(results), int(ctx.traffic.get("check_calls", 2)))
     picks = sorted(rng.choice(len(results), size=n_check, replace=False).tolist())
-    hull = R.HullReference(Y, low, high, deg)
-    X = R.Design(Y, low, high, deg)
+    X, hull = ctx.cell.model.reference(cfg, Y)
     exact = None
     worst = collections.defaultdict(float)
     for p in picks:
@@ -192,7 +168,7 @@ def check(ctx, state, results, rng) -> list[tuple[str, float]]:
         worst["draw_gap"] = max(worst["draw_gap"], draw_gap(
             idx[:k_sample], draw_uniforms(k_draw, k_sample), u_ref))
         g = np.array(jax.random.normal(k_hull_key,
-                                       (max(HULL_OVERSAMPLE * k_hull, 8), deg + 1),
+                                       (max(HULL_OVERSAMPLE * k_hull, 8), d),
                                        dtype=jnp.float32))
         g /= np.maximum(np.linalg.norm(g, axis=1, keepdims=True), 1e-12)
         worst["hull_gap"] = max(worst["hull_gap"],

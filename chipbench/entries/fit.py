@@ -1,7 +1,8 @@
 """Closed loop of coreset fits: ``fit_mctm_streaming`` back to back.
 
-Set-up builds the traffic's coresets with the program's own two-pass
-``distributed_build_coreset`` on a one-device mesh and keeps each as a user
+Set-up builds the traffic's coresets with the configuration model's own
+two-pass build (``chipbench/models/<model>.py``: ``distributed_build_coreset``
+for the MCTM) on the cell's data mesh and keeps each as a user
 holds it: float32 host rows ``Y[idx]`` and their weights. They come from
 the configuration's ``data_seed`` data set in its fixed row order, with
 keys from ``data_seed``, so every run fits the same rows. Each call is one
@@ -50,32 +51,24 @@ RECORD = "chipbench_fit_exponents.txt"
 def setup(ctx) -> dict:
     import jax
 
-    from repro.core import mctm as M
-    from repro.core.bernstein import DataScaler
-    from repro.core.distributed_coreset import distributed_build_coreset
-    from repro.utils.compat import make_mesh
-
-    cfg, traffic = ctx.config, ctx.traffic
+    cfg, traffic, model = ctx.config, ctx.traffic, ctx.cell.model
     # every run fits the same coresets: the data set in its fixed row order,
     # the builds' keys from the configuration's data_seed
-    Y = datagen.generate(cfg["dgp"], cfg["n"], cfg["data_seed"], cfg["data_seed"])
+    Y = datagen.generate(cfg["dgp"], cfg["n"], cfg["data_seed"], cfg["data_seed"],
+                         bench_dir=ctx.cell.bench_dir)
     ctx.phase("data")
-    model = M.MCTMConfig(J=cfg["J"], degree=cfg["degree"], eta=cfg["eta"],
-                         min_slope=cfg["min_slope"])
-    scaler = DataScaler.fit(Y)
-    mesh = make_mesh((1,), ("data",), devices=jax.devices()[:1])
+    prog = model.program(cfg, Y)
+    mesh = ctx.cell.data_mesh()
     base_key = jax.random.PRNGKey(cfg["data_seed"])
     coresets = []
     for j in range(int(traffic["coresets"])):
-        cs = distributed_build_coreset(
-            model, scaler, Y, cfg["k"], cfg["method"], mesh=mesh,
-            key=jax.random.fold_in(base_key, j), alpha=cfg["alpha"],
-            chunk_size=cfg["chunk"])
+        cs = model.build(cfg, prog, Y, mesh=mesh, key=jax.random.fold_in(base_key, j),
+                         sketch_size=0)
         coresets.append((Y[np.asarray(cs.indices)], np.asarray(cs.weights, np.float32)))
     ctx.phase("coreset builds")
     low, high = datagen.scaler_bounds(Y.astype(np.float64))
     cache_dir = jax.config.jax_compilation_cache_dir
-    state = {"model": model, "scaler": scaler, "mesh": mesh, "coresets": coresets,
+    state = {"program": prog, "mesh": mesh, "coresets": coresets,
              "low": low, "high": high, "seed": ctx.seed, "exponents": {},
              "record": os.path.join(cache_dir, RECORD) if cache_dir else None}
     # one step of each coreset at its own weights (e = 0) compiles every
@@ -119,8 +112,9 @@ def exponent(state, i: int) -> int:
 def fit_rows(state, Yc, wc, tr: dict) -> dict:
     from repro.core import mctm_fit
 
+    prog = state["program"]
     result = mctm_fit.fit_mctm_streaming(
-        state["model"], state["scaler"], Yc, wc, method=tr["method"],
+        prog["model"], prog["scaler"], Yc, wc, method=tr["method"],
         steps=tr["steps"], gtol=tr["gtol"], history=tr["history"],
         chunk_size=tr["chunk_size"])
     return {"theta_raw": np.asarray(result.params.theta_raw, np.float64),
@@ -154,7 +148,7 @@ def control_call(ctx, state, i: int) -> dict:
 
 
 def release(state) -> None:
-    for k in ("model", "scaler", "mesh"):
+    for k in ("program", "mesh"):
         state.pop(k, None)
 
 

@@ -2,10 +2,4 @@
 compiles less persistent-cache hits. The cache keeps only programs that took
 JAX's default of a second or more to compile, so a quicker program that a
 call re-lowers is compiled again on every call and counts here."""
-from chipbench.run import window_compiles
-
-
-def read(ctx):
-    if not ctx.results:
-        return None
-    return window_compiles(ctx) / len(ctx.results)
+from chipbench.readers import compiles_per_call as read

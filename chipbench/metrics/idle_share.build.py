@@ -1,8 +1,2 @@
 """Share of the traced window in which no operation ran on the device (%)."""
-
-
-def read(ctx):
-    t = ctx.trace_summary
-    if t is None or t.window_s <= 0 or not t.device_ops:
-        return None
-    return 100.0 * (1.0 - t.busy_s / t.window_s)
+from chipbench.readers import idle_share as read

@@ -6,5 +6,5 @@ window's length times the peak FLOP/s (%)."""
 def read(ctx):
     if ctx.trace_summary is None or ctx.peaks is None or not ctx.results:
         return None
-    need = len(ctx.results) * ctx.cost("build").flops(ctx.config, ctx.traffic)
+    need = len(ctx.results) * ctx.cost("build").flops(ctx.config, ctx.traffic, ctx.cell.model)
     return 100.0 * need / (ctx.window_s * ctx.peaks["flops_per_s"])

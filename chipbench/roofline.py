@@ -27,8 +27,9 @@ def is_kernel(kernel: str, op_name: str) -> bool:
 def least_time_s(ctx, kernel: str) -> tuple[float, str]:
     cost = ctx.cost(kernel)
     calls = len(ctx.results)
-    t_flops = calls * cost.flops(ctx.config, ctx.traffic) / ctx.peaks["flops_per_s"]
-    t_bytes = calls * cost.bytes(ctx.config, ctx.traffic) / ctx.peaks["hbm_bytes_per_s"]
+    model = ctx.cell.model
+    t_flops = calls * cost.flops(ctx.config, ctx.traffic, model) / ctx.peaks["flops_per_s"]
+    t_bytes = calls * cost.bytes(ctx.config, ctx.traffic, model) / ctx.peaks["hbm_bytes_per_s"]
     return (t_flops, "flops") if t_flops >= t_bytes else (t_bytes, "bytes")
 
 

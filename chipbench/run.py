@@ -6,9 +6,14 @@ Everything about a cell is found by name from files of its own, so a cell,
 a configuration, a traffic mix or a metric is added by adding files:
 
 - ``BENCHMARK.json`` (checkout root): the cells and the metrics;
-- ``chipbench/configs/<config>.json``: the deployment's sizes;
+- ``chipbench/configs/<config>.json``: the deployment's sizes; its
+  ``"dgp"`` names the generator ``chipbench/data/<dgp>.py`` of its rows,
+  and its ``"model_module"`` (``mctm`` where absent) the model
+  ``chipbench/models/<model>.py``: the program objects a build is given,
+  the build call, its float64 reference and its widths;
 - ``chipbench/traffic/<traffic>.json``: the mix; its ``"entry"`` names the
-  loop in ``chipbench/entries/<entry>.py`` that drives the program;
+  loop in ``chipbench/entries/<entry>.py`` that drives the program, on a
+  data mesh over the cell's ``chips`` devices;
 - ``chipbench/limits/<workload>.json``: the limit of each compared number;
 - ``chipbench/metrics/<metric>.py``: a ``read(ctx)`` returning the value,
   or None where the run has nothing to read;
@@ -51,6 +56,9 @@ ROOT = os.path.dirname(HERE)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+# the model a configuration runs where it names none
+DEFAULT_MODEL = "mctm"
+
 LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
@@ -88,6 +96,7 @@ class Cell:
     traffic: dict
     limits: dict
     entry: object
+    model: object
     end_to_end: list
     per_layer: list
     bench_dir: str
@@ -95,6 +104,19 @@ class Cell:
     @property
     def name(self) -> str:
         return self.workload["name"]
+
+    @property
+    def chips(self) -> int:
+        return int(self.workload["chips"])
+
+    def data_mesh(self):
+        """The one-axis ``data`` mesh a call runs on: the first ``chips``
+        devices."""
+        import jax
+
+        from repro.utils.compat import make_mesh
+
+        return make_mesh((self.chips,), ("data",), devices=jax.devices()[:self.chips])
 
 
 def _applies(metric: dict, workload: str, reported: set[str] | None) -> bool:
@@ -118,10 +140,13 @@ def find_cell(workload: str, root: str = ROOT) -> Cell:
     limits = _load_json(limits_path) if os.path.exists(limits_path) else {}
     entry = load_module(os.path.join(bench_dir, "entries", traffic["entry"] + ".py"),
                         "chipbench_entry_" + traffic["entry"])
+    model_name = config.get("model_module", DEFAULT_MODEL)
+    model = load_module(os.path.join(bench_dir, "models", model_name + ".py"),
+                        "chipbench_model_" + model_name)
     e2e = [m for m in bench["end_to_end"] if _applies(m, workload, None)]
     reported = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"] if _applies(m, workload, reported)]
-    return Cell(wl, config, traffic, limits, entry, e2e, per_layer, bench_dir)
+    return Cell(wl, config, traffic, limits, entry, model, e2e, per_layer, bench_dir)
 
 
 def metric_reader(cell: Cell, name: str):
@@ -169,7 +194,8 @@ class RunContext:
         return float(sum(self.cell.entry.work(self, r).get(key, 0) for r in self.results))
 
     def cost(self, kernel: str):
-        """The cost module of a kernel: ``flops(ctx)``, ``bytes(ctx)`` per call."""
+        """The cost module of a kernel: ``flops``, ``bytes`` per call, of the
+        configuration, the mix and (a build's) the model."""
         path = os.path.join(self.cell.bench_dir, "costs", kernel + ".py")
         return load_module(path, "chipbench_cost_" + kernel)
 
@@ -297,11 +323,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         from repro.utils.compile_cache import enable_compile_cache
 
         enable_compile_cache()
-        device, peaks = device_info(int(cell.workload["chips"]),
-                                    os.path.join(cell.bench_dir, "peaks.json"))
+        device, peaks = device_info(cell.chips, os.path.join(cell.bench_dir, "peaks.json"))
     else:
         d = jax.devices()[0]
-        device, peaks = {"platform": d.platform, "kind": d.device_kind, "count": 1}, None
+        device, peaks = {"platform": d.platform, "kind": d.device_kind,
+                         "count": cell.chips}, None
     counter = EventCounter()
     jax.monitoring.register_event_duration_secs_listener(counter)
     jax.monitoring.register_event_listener(counter)
@@ -414,8 +440,7 @@ def control_main(argv=None) -> int:
     from repro.utils.compile_cache import enable_compile_cache
 
     enable_compile_cache()
-    device, peaks = device_info(int(cell.workload["chips"]),
-                                os.path.join(cell.bench_dir, "peaks.json"))
+    device, peaks = device_info(cell.chips, os.path.join(cell.bench_dir, "peaks.json"))
     control_seeds = set(args.seeds if args.control_seeds is None else args.control_seeds)
     for seed in args.seeds:
         ctx = RunContext(cell, seed, 0.0, False, peaks=peaks, device=device)

@@ -22,6 +22,9 @@ def _cost(kernel):
     return run.load_module(os.path.join(lib.BENCH, "costs", kernel + ".py"), "cost_" + kernel)
 
 
+MCTM = run.load_module(os.path.join(lib.BENCH, "models", "mctm.py"), "model_mctm")
+
+
 TWO = {"sketch_factor": 0}
 ONE = {"sketch_factor": 4}
 
@@ -32,32 +35,32 @@ N2, N10 = 2**24 + 1, 2**22 + 1
 
 def test_gram_by_hand():
     g = _cost("gram")
-    assert g.flops(_cfg("mctm_j2_mixture"), TWO) == 2 * N2 * 14 * 14
-    assert g.bytes(_cfg("mctm_j2_mixture"), TWO) == 4 * (N2 * 14 + 1025 * 196)
+    assert g.flops(_cfg("mctm_j2_mixture"), TWO, MCTM) == 2 * N2 * 14 * 14
+    assert g.bytes(_cfg("mctm_j2_mixture"), TWO, MCTM) == 4 * (N2 * 14 + 1025 * 196)
     # J=10: D = 70, ⌈(2^22+1)/16384⌉ = 257 chunks
-    assert g.flops(_cfg("mctm_j10_covertype"), TWO) == 2 * N10 * 70 * 70
-    assert g.bytes(_cfg("mctm_j10_covertype"), TWO) == 4 * (N10 * 70 + 257 * 4900)
+    assert g.flops(_cfg("mctm_j10_covertype"), TWO, MCTM) == 2 * N10 * 70 * 70
+    assert g.bytes(_cfg("mctm_j10_covertype"), TWO, MCTM) == 4 * (N10 * 70 + 257 * 4900)
 
 
 def test_extremes_by_hand():
     e = _cost("extremes")
-    assert e.flops(_cfg("mctm_j2_mixture"), TWO) == 2 * (2 * N2) * 7 * 1614
-    assert e.bytes(_cfg("mctm_j2_mixture"), TWO) == 4 * (2 * N2 * 7 + 1025 * 1614 * 11)
-    assert e.flops(_cfg("mctm_j10_covertype"), TWO) == 2 * (10 * N10) * 7 * 1614
+    assert e.flops(_cfg("mctm_j2_mixture"), TWO, MCTM) == 2 * (2 * N2) * 7 * 1614
+    assert e.bytes(_cfg("mctm_j2_mixture"), TWO, MCTM) == 4 * (2 * N2 * 7 + 1025 * 1614 * 11)
+    assert e.flops(_cfg("mctm_j10_covertype"), TWO, MCTM) == 2 * (10 * N10) * 7 * 1614
     # ≈ 0.76 TFLOP a build at J=2: 3.9 ms at 197 TFLOP/s, compute-bound
-    assert e.flops(_cfg("mctm_j2_mixture"), TWO) / 197e12 == pytest.approx(3.85e-3, rel=0.01)
+    assert e.flops(_cfg("mctm_j2_mixture"), TWO, MCTM) / 197e12 == pytest.approx(3.85e-3, rel=0.01)
 
 
 def test_sweep_counts_the_sketch_as_a_scatter_add():
     s = _cost("sweep")
     cfg = _cfg("mctm_j2_mixture")
     extremes = 2 * (2 * N2) * 7 * 1614
-    assert s.flops(cfg, ONE) == 3 * N2 * 14 + extremes
+    assert s.flops(cfg, ONE, MCTM) == 3 * N2 * 14 + extremes
     # no sketch-sized factor per row: a one-hot matmul would add 2·n·784·14
-    assert s.flops(cfg, ONE) < extremes * 1.001
+    assert s.flops(cfg, ONE, MCTM) < extremes * 1.001
     rows_in = N2 * 14 + 2 * N2 * 7 + 2 * N2
     per_chunk = 1614 * 11 + 2 * 784 * 14
-    assert s.bytes(cfg, ONE) == 4 * (rows_in + N2 * 14 + 1025 * per_chunk)
+    assert s.bytes(cfg, ONE, MCTM) == 4 * (rows_in + N2 * 14 + 1025 * per_chunk)
 
 
 def test_whole_build_by_hand():
@@ -65,13 +68,13 @@ def test_whole_build_by_hand():
     cfg = _cfg("mctm_j2_mixture")
     basis = 8 * 2 * N2 * 7 * 2
     two = basis + 2 * N2 * 196 + (2 * N2 * 196 + 2 * N2 * 14) + 2 * 2 * N2 * 7 * 1614
-    assert b.flops(cfg, TWO) == two
+    assert b.flops(cfg, TWO, MCTM) == two
     one = basis / 2 + 3 * N2 * 14 + (2 * N2 * 196 + 2 * N2 * 14) + 2 * 2 * N2 * 7 * 1614
-    assert b.flops(cfg, ONE) == one
+    assert b.flops(cfg, ONE, MCTM) == one
     cfg10 = _cfg("mctm_j10_covertype")
     ten = (8 * 10 * N10 * 7 * 2 + 2 * N10 * 4900 + (2 * N10 * 4900 + 2 * N10 * 70)
            + 2 * 10 * N10 * 7 * 1614)
-    assert b.flops(cfg10, TWO) == ten
+    assert b.flops(cfg10, TWO, MCTM) == ten
 
 
 FIT = {"steps": 400}

@@ -4,8 +4,10 @@ Runs on the CPU and loads no TPU library: the chip checks are driven with
 JAX's CPU backend or with a stand-in device."""
 from __future__ import annotations
 
+import hashlib
 import json
 import os
+import shutil
 import subprocess
 import sys
 
@@ -59,6 +61,87 @@ def test_throwaway_config_and_mix_are_found_from_new_files(tmp_path):
     assert not os.path.exists(os.path.join(lib.BENCH, "traffic", "tiny_two_pass.json"))
 
 
+def test_every_accepted_cell_runs_the_mctm_on_a_mesh_of_its_chips():
+    run.import_program(lib.ROOT)
+    for wl in _bench()["workloads"]:
+        cell = run.find_cell(wl["name"])
+        # no configuration names a model, so each runs the default
+        assert "model_module" not in cell.config and run.DEFAULT_MODEL == "mctm"
+        assert cell.model.__file__ == os.path.join(lib.BENCH, "models", "mctm.py")
+        mesh = cell.data_mesh()
+        assert mesh.axis_names == ("data",) and mesh.devices.size == wl["chips"]
+
+
+def _digest(paths) -> str:
+    """SHA-256 over the names and bytes of the files under ``paths``."""
+    h = hashlib.sha256()
+    for top in paths:
+        files = [top] if os.path.isfile(top) else sorted(
+            os.path.join(d, f) for d, dirs, fs in os.walk(top) for f in fs
+            if "__pycache__" not in d)
+        for path in files:
+            h.update(os.path.relpath(path, lib.ROOT).encode())
+            with open(path, "rb") as f:
+                h.update(f.read())
+    return h.hexdigest()
+
+
+def test_new_generator_model_and_two_chip_cell_run_from_new_files(tmp_path):
+    """A configuration that brings its own generator and model file, in a
+    cell on two chips, is found, run and judged from new files alone: here
+    renamed copies of ``normal_mixture.py`` and ``mctm.py``, run in a child
+    process with two host CPU devices."""
+    repo = [os.path.join(lib.ROOT, "BENCHMARK.json"), lib.BENCH]
+    before = _digest(repo)
+    root = lib.tiny_bench(tmp_path)
+    bench_dir = os.path.join(root, "chipbench")
+    shutil.copy(os.path.join(bench_dir, "data", "normal_mixture.py"),
+                os.path.join(bench_dir, "data", "mixture_copy.py"))
+    shutil.copy(os.path.join(bench_dir, "models", "mctm.py"),
+                os.path.join(bench_dir, "models", "mctm_copy.py"))
+    with open(os.path.join(bench_dir, "configs", "tiny_j2.json")) as f:
+        cfg = json.load(f)
+    cfg.update(name="tiny_j2_copy", dgp="mixture_copy", model_module="mctm_copy")
+    with open(os.path.join(bench_dir, "configs", "tiny_j2_copy.json"), "w") as f:
+        json.dump(cfg, f)
+    shutil.copy(os.path.join(bench_dir, "limits", "tiny.build.two_pass.json"),
+                os.path.join(bench_dir, "limits", "tiny.build.two_chips.json"))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    bench["configs"].append({"name": "tiny_j2_copy", "source": "test", "reduced": ["n"],
+                             "file": "chipbench/configs/tiny_j2_copy.json", "why": "test"})
+    bench["workloads"].append({"name": "tiny.build.two_chips", "config": "tiny_j2_copy",
+                               "traffic": "tiny_two_pass", "chips": 2, "why": "test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "tiny.build.two_pass" in m.get("workloads", ()):
+            m["workloads"].append("tiny.build.two_chips")
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+
+    code = f"""
+import json, sys
+sys.path[:0] = [{lib.ROOT!r}, {os.path.join(lib.ROOT, "src")!r}]
+from chipbench import run
+cell = run.find_cell("tiny.build.two_chips", root={root!r})
+rec = run.run_cell(cell, 2**31 + 9, 0.5, False, root={lib.ROOT!r}, on_chip=False)
+print(json.dumps({{"model": cell.model.__file__, "dgp": cell.config["dgp"],
+                  "mesh": int(cell.data_mesh().devices.size), **rec}}))
+"""
+    flags = os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=2"
+    env = dict(os.environ, JAX_PLATFORMS="cpu", XLA_FLAGS=flags.strip())
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
+                          env=env, timeout=600, cwd=root)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["correct"], out["checks"]
+    assert out["attempted"] >= 1 and out["failed"] == 0
+    assert out["mesh"] == 2 and out["device"]["count"] == 2
+    assert out["model"] == os.path.join(bench_dir, "models", "mctm_copy.py")
+    assert out["dgp"] == "mixture_copy"
+    assert not os.path.exists(os.path.join(lib.BENCH, "data", "mixture_copy.py"))
+    assert _digest(repo) == before
+
+
 def test_run_refuses_a_cpu_backend():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
@@ -110,7 +193,7 @@ def test_build_and_fit_metrics_stay_in_their_own_cells():
     for wl in _bench()["workloads"]:
         cell = run.find_cell(wl["name"])
         names = {m["name"] for m in cell.end_to_end + cell.per_layer}
-        kind = cell.traffic["entry"]
+        kind = cell.entry.NAME
         # a metric named for a kind of call applies only to cells of that kind
         suffixed = [n.rsplit(".", 1)[1] for n in names if "." in n]
         assert all(s == kind for s in suffixed), (wl["name"], names)

@@ -61,9 +61,10 @@ def test_kernels_are_found_by_their_custom_call():
 
 def test_roofline_share_by_hand():
     t = _hand_made()
-    cost = types.SimpleNamespace(flops=lambda c, tr: 1.0e3, bytes=lambda c, tr: 8.0e3)
+    cost = types.SimpleNamespace(flops=lambda c, tr, m: 1.0e3, bytes=lambda c, tr, m: 8.0e3)
     ctx = types.SimpleNamespace(
         trace_summary=t, results=[0, 1], config={}, traffic={},
+        cell=types.SimpleNamespace(model=None),
         peaks={"flops_per_s": 1e12, "hbm_bytes_per_s": 1e12}, cost=lambda k: cost)
     # two calls: 2e3 flops → 2 ns, 16e3 bytes → 16 ns; extremes ran 100 ns
     assert roofline.least_time_s(ctx, "extremes") == (pytest.approx(16e-9), "bytes")
